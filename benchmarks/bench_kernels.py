@@ -13,6 +13,7 @@ from repro.kernels.aopt_gains.ref import aopt_gains_ref
 from repro.kernels.flash_attention.ref import flash_attention_ref
 from repro.kernels.logistic_gains.ref import logistic_gains_ref
 from repro.kernels.marginal_gains.ref import regression_gains_ref
+from repro.utils.compile_cache import enable_compile_cache
 
 RNG = np.random.default_rng(0)
 
@@ -358,6 +359,7 @@ def run(autotune: bool = False):
 
 
 def main() -> None:
+    enable_compile_cache()
     import argparse
     import json
 

@@ -56,6 +56,7 @@ from repro.data.synthetic import (
     make_d3_classification,
     make_d4_gene,
 )
+from repro.utils.compile_cache import enable_compile_cache
 
 KEY = jax.random.PRNGKey(0)
 
@@ -879,6 +880,7 @@ def run(full: bool = False):
 
 
 def main() -> None:
+    enable_compile_cache()
     import argparse
     import json
 

@@ -9,8 +9,11 @@ Emits ``name,us_per_call,derived`` CSV rows:
 import argparse
 import sys
 
+from repro.utils.compile_cache import enable_compile_cache
+
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true",
                     help="paper-scale dataset sizes")

@@ -33,9 +33,11 @@ from repro.core.dash import DashConfig
 from repro.core.distributed import dash_distributed, pad_ground_set
 from repro.data.synthetic import make_d1_design
 from repro.launch.mesh import make_host_mesh
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     X = make_d1_design(seed=0, n_samples=512, n_features=128)
     k = 32
 

@@ -14,9 +14,11 @@ from repro.core import (
     top_k_select,
 )
 from repro.data.synthetic import make_d1_regression
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     X, y, support = make_d1_regression(seed=0, n_samples=600,
                                        n_features=200, support=40)
     k = 40

@@ -13,9 +13,11 @@ import jax.numpy as jnp
 from repro.configs import get_reduced_config
 from repro.models import build_model
 from repro.train.serve import generate
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="h2o-danube-1.8b")
     ap.add_argument("--batch", type=int, default=4)

@@ -26,6 +26,7 @@ from repro.serve import (
     SelectRequest,
     SelectionServer,
 )
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def make_server(chaos=None):
@@ -58,6 +59,7 @@ def offered_load():
 
 
 def main():
+    enable_compile_cache()
     baseline = make_server().serve(offered_load())
 
     chaotic = make_server(chaos=FailureInjector(fail_at=(1,)))

@@ -24,11 +24,13 @@ from repro.data.selection import BatchSelector
 from repro.data.synthetic import make_lm_tokens
 from repro.models import build_model
 from repro.train.loop import train_loop
+from repro.utils.compile_cache import enable_compile_cache
 
 logging.basicConfig(level=logging.INFO, format="%(message)s")
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-135m")
     ap.add_argument("--steps", type=int, default=300)

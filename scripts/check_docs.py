@@ -89,7 +89,7 @@ def known_flags(repo: Path = REPO) -> set[str]:
 #: Runtime-generated artifacts the docs legitimately name although they
 #: are not tracked in the tree.
 _GENERATED = re.compile(
-    r"^(BENCH_\w+\.json|manifest\.json|tuning\.json)$")
+    r"^(BENCH_\w+\.json|manifest\.json|tuning\.json|\.jax_cache)$")
 
 
 def _is_placeholder(tok: str) -> bool:
@@ -119,7 +119,7 @@ def _check_pathlike(tok: str, repo: Path, problems: list[str],
                     where: str) -> None:
     path_part, _, symbol = tok.partition("::")
     if path_part.startswith(("~", "/")) or \
-            _GENERATED.match(path_part.rsplit("/", 1)[-1]):
+            _GENERATED.match(path_part.rstrip("/").rsplit("/", 1)[-1]):
         return
     target = _resolve_path(path_part, repo)
     if target is None:

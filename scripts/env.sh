@@ -1,9 +1,9 @@
 # Host environment for benchmark runs — `source scripts/env.sh`.
 #
 # Pins the knobs that make wall-clock numbers comparable across hosts
-# and runs; sourced by both CI bench invocations and the tpu-bench
-# workflow.  Everything is guarded so sourcing on a box without the
-# optional pieces (tcmalloc, TPU runtime) is a no-op for that piece.
+# and runs; sourced by the CI bench invocations.  Everything is guarded
+# so sourcing on a box without the optional pieces (tcmalloc, TPU
+# runtime) is a no-op for that piece.
 
 # Faster malloc for the host-side driver loops, when present.  The
 # LD_PRELOAD is guarded: preloading a missing .so makes EVERY child

@@ -418,9 +418,9 @@ def select_batched(algo: str, obj, k: int, keys, *, opt=None, alpha=None,
         opts_key = tuple(sorted(opts.items()))
         runner = cached_runner(
             obj, ("select_batched_det", algo, k, opts_key),
-            lambda: jax.jit(lambda: spec.single(obj, k, None, **opts)),
+            lambda: jax.jit(lambda o: spec.single(o, k, None, **opts)),
         )
-        res = _normalize(runner())
+        res = _normalize(runner(obj))
         return jax.tree_util.tree_map(
             lambda x: jnp.broadcast_to(x, (B,) + jnp.shape(x)), res
         )
@@ -445,18 +445,19 @@ def select_batched(algo: str, obj, k: int, keys, *, opt=None, alpha=None,
                         jnp.float32).reshape(-1), (B,))
         runner = cached_runner(
             obj, ("select_batched", "dash", k, B, cfg),
-            lambda: jax.jit(
-                jax.vmap(lambda kk, g, a: dash(obj, cfg, kk, g, a))),
+            lambda: jax.jit(jax.vmap(
+                lambda o, kk, g, a: dash(o, cfg, kk, g, a),
+                in_axes=(None, 0, 0, 0))),
         )
-        return _normalize(runner(keys, opt, alpha))
+        return _normalize(runner(obj, keys, opt, alpha))
 
     opts_key = tuple(sorted(opts.items()))
     # Normalize INSIDE the vmap so sel_count is per-request, not a sum
     # over the whole batch of masks.
     runner = cached_runner(
         obj, ("select_batched", algo, k, B, opts_key),
-        lambda: jax.jit(
-            jax.vmap(lambda kk: _normalize(spec.single(obj, k, kk,
-                                                       **opts)))),
+        lambda: jax.jit(jax.vmap(
+            lambda o, kk: _normalize(spec.single(o, k, kk, **opts)),
+            in_axes=(None, 0))),
     )
-    return runner(keys)
+    return runner(obj, keys)

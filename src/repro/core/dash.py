@@ -178,11 +178,11 @@ def dash(obj, cfg: DashConfig, key, opt: float | jnp.ndarray,
 def _checkpointed_step_runner(obj, cfg: DashConfig):
     """One jitted DASH round with (ρ, OPT, α) as runtime inputs — a
     single compilation serves every round of every resumed run."""
-    def build():
-        body = make_round_body(_single_device_hooks(obj, cfg), cfg)
-        return jax.jit(body)
+    def step(o, rho, carry, opt, alpha):
+        body = make_round_body(_single_device_hooks(o, cfg), cfg)
+        return body(rho, carry, opt, alpha)
 
-    return cached_runner(obj, ("ckpt_step", cfg), build)
+    return cached_runner(obj, ("ckpt_step", cfg), lambda: jax.jit(step))
 
 
 def dash_checkpointed(
@@ -226,7 +226,7 @@ def dash_checkpointed(
                 read_manifest(resilience.ckpt_dir, snap)["extra"]["round"])
 
     carry = drive_checkpointed_rounds(
-        lambda rho, c, arrived: step(rho, c, opt_v, alpha_v),
+        lambda rho, c, arrived: step(obj, rho, c, opt_v, alpha_v),
         carry, cfg, resilience=resilience, start_round=start_round,
         failure_injector=failure_injector, deadline=deadline,
         snapshot_extra={"algo": "dash", "n": int(obj.n)},
@@ -242,7 +242,8 @@ def dash_checkpointed(
     )
 
 
-def opt_guess_lattice(obj, eps: float, n_guesses: int, k: int | None = None):
+def opt_guess_lattice(obj, eps: float, n_guesses: int, k: int | None = None,
+                      *, top_gain=None):
     """OPT guesses spanning [max_a f(a), k·max_a f(a)] geometrically.
 
     The paper (App. G) uses OPT ∈ {(1+ε)^i·max_a f(a) : i ≤ ln(n)/ε};
@@ -255,8 +256,14 @@ def opt_guess_lattice(obj, eps: float, n_guesses: int, k: int | None = None):
     minimax-regret point of the range in log space.  (The old ratio
     formula's ``1/max(n_guesses − 1, 1)`` exponent silently pinned
     ``n_guesses=1`` to the degenerate lower endpoint g0.)
+
+    ``top_gain`` is max_a f(a) when the caller already has it — the
+    sharded runtimes compute it shard by shard, since a sweep of the
+    whole ground set would gather X onto every device.
     """
-    g0 = jnp.maximum(jnp.max(obj.gains(obj.init())), 1e-12)
+    if top_gain is None:
+        top_gain = jnp.max(obj.gains(obj.init()))
+    g0 = jnp.maximum(top_gain, 1e-12)
     hi = float(k) if k else 1.0 / eps
     if n_guesses == 1:
         return g0 * jnp.sqrt(jnp.asarray(hi, jnp.float32))[None]
@@ -294,20 +301,22 @@ def _best_of_lattice(results: DashResult) -> DashResult:
 
 
 def _lattice_runner(obj, cfg: DashConfig, batched: bool):
-    """Jitted lattice executors, cached per objective (weakly — see
-    :func:`core.selection_loop.cached_runner`).
+    """Jitted lattice executors ``run(obj, keys, opts, alphas)``, cached
+    per objective (weakly — see :func:`core.selection_loop.cached_runner`).
 
     ``dash_auto`` is called repeatedly with the same objective (guess
     sweeps, benchmarks, retries with fresh keys); building the jit
     wrapper inline would discard XLA's compilation cache every call and
-    turn each invocation into a full retrace.
+    turn each invocation into a full retrace.  The objective is the
+    first ARGUMENT, so its dataset is a parameter of the program.
     """
+    def run(o, kk, g, a):
+        return dash(o, cfg, kk, g, a)
+
     def build():
         if batched:
-            return jax.jit(
-                jax.vmap(lambda kk, g, a: dash(obj, cfg, kk, g, a))
-            )
-        return jax.jit(lambda kk, g, a: dash(obj, cfg, kk, g, a))
+            return jax.jit(jax.vmap(run, in_axes=(None, 0, 0, 0)))
+        return jax.jit(run)
 
     return cached_runner(obj, ("lattice", cfg, batched), build)
 
@@ -362,13 +371,14 @@ def dash_auto(
     keys = jax.random.split(key, n_runs)
 
     if guess_mode in ("batched", "vmap"):
-        results = _lattice_runner(obj, cfg, True)(keys, opts, alphas)
+        results = _lattice_runner(obj, cfg, True)(obj, keys, opts, alphas)
     else:
         # Debug path: one trace (jit outside the loop — the old code
         # retraced dash per guess), still no per-guess host sync: results
         # are stacked and reduced on device.
         run = _lattice_runner(obj, cfg, False)
-        per_guess = [run(keys[i], opts[i], alphas[i]) for i in range(n_runs)]
+        per_guess = [run(obj, keys[i], opts[i], alphas[i])
+                     for i in range(n_runs)]
         results = jax.tree_util.tree_map(
             lambda *xs: jnp.stack(xs), *per_guess
         )

@@ -341,22 +341,31 @@ def _make_guess_runner(obj, cfg: DashConfig, n_local: int, n_global: int,
 
 
 def _shard_mapped(run, mesh, in_specs, out_specs):
-    """shard_map across jax versions, replication checking off: the
-    Monte-Carlo estimators vmap over sample keys with collectives
-    (psum/all_gather) inside the vmapped body; the VMA/rep invariant
-    checker does not yet support that composition."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            run, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    # jax < 0.6: experimental API, check_vma was called check_rep
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(
+    """``jax.shard_map`` with replication checking off: the Monte-Carlo
+    estimators vmap over sample keys with collectives (psum/all_gather)
+    inside the vmapped body; the VMA invariant checker does not support
+    that composition."""
+    return jax.shard_map(
         run, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False,
+        check_vma=False,
     )
+
+
+def _top_gain(obj, mesh, model_axis: str):
+    """max_a f(a) at S = ∅, swept shard by shard: the opening probe of
+    the OPT guess lattice.  A sweep of the global objective would pass
+    the sharded X to a kernel that cannot be partitioned, and the
+    compiler would gather all of X onto every device."""
+    def top(X_local):
+        g = obj.dist_gains(obj.dist_init(X_local), X_local)
+        return jax.lax.pmax(jnp.max(g), model_axis)
+
+    run = cached_runner(
+        obj, ("top_gain", mesh, model_axis),
+        lambda: jax.jit(_shard_mapped(top, mesh, (P(None, model_axis),),
+                                      P())),
+    )
+    return run(obj.X)
 
 
 def _resolve_engine_flag(obj, use_filter_engine: bool | None) -> bool:
@@ -1006,7 +1015,8 @@ def dash_auto_distributed(
     Pp = mesh.shape[pod_axis]
     Pm = mesh.shape[model_axis]
     assert n % Pm == 0, f"pad ground set: n={n} % model={Pm}"
-    guesses = opt_guess_lattice(obj, eps, n_guesses, k)
+    guesses = opt_guess_lattice(obj, eps, n_guesses, k,
+                                top_gain=_top_gain(obj, mesh, model_axis))
     opts, alphas_arr = lattice_grid(
         guesses, [alpha] if alphas is None else alphas
     )
@@ -1482,7 +1492,8 @@ def fast_distributed(
     else:
         from repro.core.dash import opt_guess_lattice
 
-        guesses = opt_guess_lattice(obj, eps, n_guesses, k)
+        guesses = opt_guess_lattice(
+            obj, eps, n_guesses, k, top_gain=_top_gain(obj, mesh, model_axis))
     run = _fast_dist_runner(obj, k, mesh, n_local, n, model_axis, eps,
                             r_max, int(guesses.shape[0]), engine)
     sel, count, value, rounds, values, opt_used = run(obj.X, key, guesses)

@@ -323,13 +323,13 @@ def fast(
         guesses = opt_guess_lattice(obj, eps, n_guesses, k)
     G = int(guesses.shape[0])
 
-    def build():
-        core = _make_fast_core(obj, k, eps, r_max, engine)
-        return jax.jit(
-            lambda kk, gg: binary_search_opt(core, kk, gg, eps))
+    def run(o, kk, gg):
+        core = _make_fast_core(o, k, eps, r_max, engine)
+        return binary_search_opt(core, kk, gg, eps)
 
-    runner = cached_runner(obj, ("fast", k, eps, r_max, engine, G), build)
-    return runner(key, guesses)
+    runner = cached_runner(obj, ("fast", k, eps, r_max, engine, G),
+                           lambda: jax.jit(run))
+    return runner(obj, key, guesses)
 
 
 def fast_cost(n: int, k: int, eps: float = 0.06) -> dict:

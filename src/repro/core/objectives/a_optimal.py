@@ -30,7 +30,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.objectives.base import gather_columns
+from repro.core.objectives.base import PytreeObject, gather_columns
 from repro.kernels.common import quantize, resolve_precision
 
 
@@ -52,7 +52,7 @@ class AOptDistState(NamedTuple):
     W: jnp.ndarray          # (d, n_local) — shard-local
 
 
-class AOptimalityObjective:
+class AOptimalityObjective(PytreeObject):
     """Bayesian A-optimality oracle.  X: (d, n) stimuli columns."""
 
     def __init__(

@@ -182,6 +182,55 @@ class DistributedObjective(Objective, Protocol):
         ``Cs``/``masks`` stack ``n_samples`` gathered sets."""
 
 
+# Per-object caches that neither a pytree view nor a precision view
+# shares with its parent.
+_CACHE_ATTRS = ("_precision_views", "_selection_runner_cache")
+
+
+class PytreeObject:
+    """Base of every objective: the instance is a JAX pytree.
+
+    Attributes holding sizes, flags, floats and strings are static and
+    part of the tree structure; every other attribute (the dataset
+    arrays, derived caches, a nested objective such as a diversified
+    objective's base) is a child.  So an objective is passed to
+    ``jax.jit`` as an ARGUMENT: its dataset enters a compiled program as
+    a parameter, never as a constant baked into the executable, and one
+    compilation serves every objective of the same structure and shapes.
+    """
+
+    def __init_subclass__(cls, **kw):
+        super().__init_subclass__(**kw)
+        jax.tree_util.register_pytree_node(
+            cls, _flatten_object, lambda aux, leaves, _cls=cls:
+                _unflatten_object(_cls, aux, leaves))
+
+
+_STATIC_TYPES = (bool, int, float, str, type(None))
+
+
+def _flatten_object(obj):
+    names, leaves, static = [], [], []
+    for name in sorted(vars(obj)):
+        if name in _CACHE_ATTRS:
+            continue
+        v = vars(obj)[name]
+        if isinstance(v, _STATIC_TYPES):
+            static.append((name, v))
+        else:
+            names.append(name)
+            leaves.append(v)
+    return leaves, (tuple(names), tuple(static))
+
+
+def _unflatten_object(cls, aux, leaves):
+    names, static = aux
+    obj = object.__new__(cls)
+    obj.__dict__.update(static)
+    obj.__dict__.update(zip(names, leaves))
+    return obj
+
+
 def with_precision(obj, precision: str | None):
     """A view of ``obj`` running its kernels at ``precision``.
 

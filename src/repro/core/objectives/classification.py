@@ -34,7 +34,11 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.objectives.base import gather_columns, write_accepted_column
+from repro.core.objectives.base import (
+    PytreeObject,
+    gather_columns,
+    write_accepted_column,
+)
 from repro.kernels.common import quantize, resolve_precision
 
 
@@ -67,7 +71,7 @@ class ClassificationDistState(NamedTuple):
     eta: jnp.ndarray        # (d,) current logits X_S w
 
 
-class ClassificationObjective:
+class ClassificationObjective(PytreeObject):
     """ℓ_class feature selection oracle.  X: (d, n), y: (d,) ∈ {0,1}."""
 
     def __init__(

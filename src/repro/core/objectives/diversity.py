@@ -17,8 +17,10 @@ from typing import NamedTuple
 
 import jax.numpy as jnp
 
+from repro.core.objectives.base import PytreeObject
 
-class ClusterDiversity:
+
+class ClusterDiversity(PytreeObject):
     """d(S) = weight · Σ_c sqrt(count_c(S)) over a ground-set partition."""
 
     def __init__(self, clusters: jnp.ndarray, n_clusters: int, weight: float = 1.0):
@@ -66,7 +68,7 @@ class DiversityState(NamedTuple):
     value: jnp.ndarray      # () f32
 
 
-class DiversityObjective:
+class DiversityObjective(PytreeObject):
     """Pure cluster-coverage diversity as a standalone ``Objective``.
 
     d(S) alone is monotone SUBMODULAR (not merely differentially
@@ -109,7 +111,7 @@ class DiversityObjective:
         return self.add_set(state, idx, jnp.ones((1,), bool))
 
 
-class DiversifiedObjective:
+class DiversifiedObjective(PytreeObject):
     """f_div(S) = f(S) + d(S): wraps any base objective with diversity."""
 
     def __init__(self, base, diversity: ClusterDiversity):

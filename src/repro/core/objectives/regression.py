@@ -34,7 +34,11 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.objectives.base import gather_columns, write_accepted_column
+from repro.core.objectives.base import (
+    PytreeObject,
+    gather_columns,
+    write_accepted_column,
+)
 from repro.kernels.common import quantize, resolve_precision
 
 
@@ -132,7 +136,7 @@ def mgs_expand(Q, count, resid, C, kmax: int, span_tol: float = 1e-6):
     return D, r
 
 
-class RegressionObjective:
+class RegressionObjective(PytreeObject):
     """ℓ_reg feature selection oracle.  X: (d, n) columns, y: (d,)."""
 
     def __init__(

@@ -62,16 +62,17 @@ _RUNNER_CACHE_FALLBACK_MAX = 16
 def cached_runner(obj, key, build: Callable[[], Any]):
     """Per-objective cache for jitted selection-loop executors.
 
-    Both runtimes build their jitted runners from (objective, config,
-    layout) closures; rebuilding per call would retrace and recompile
-    every invocation, while a global ``lru_cache`` keyed on the
-    objective would strongly pin each dead objective's device-resident
-    dataset (X, y, caches) until enough entries accumulate.  The cache
-    therefore lives ON the objective (the runner closures reference the
-    objective anyway, so the reference cycle is internal and the GC
-    frees runners, executables and buffers together when the objective
-    is dropped).  ``key`` is any hashable residual (config, mesh, axes,
-    flags).
+    Both runtimes build their jitted runners per (objective, config,
+    layout); rebuilding per call would retrace and recompile every
+    invocation, while a global ``lru_cache`` keyed on the objective
+    would strongly pin each dead objective's device-resident dataset
+    (X, y, caches) until enough entries accumulate.  The cache therefore
+    lives ON the objective, so the GC frees runners and executables
+    together with the objective.  Single-device runners take the
+    objective (a pytree — ``objectives.base.PytreeObject``) as a jit
+    ARGUMENT, so its arrays are program parameters and never constants
+    baked into the executable.  ``key`` is any hashable residual
+    (config, mesh, axes, flags).
     """
     try:
         per_obj = obj.__dict__.setdefault(_RUNNER_CACHE_ATTR, {})

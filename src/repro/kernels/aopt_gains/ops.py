@@ -13,7 +13,6 @@ from __future__ import annotations
 from repro.kernels.aopt_gains.kernel import aopt_gains_pallas
 from repro.kernels.aopt_gains.ref import aopt_gains_ref
 from repro.kernels.common import (
-    HUGE_ELEMS,
     pad2d,
     quantize,
     resolve_path,
@@ -41,7 +40,7 @@ def aopt_gains(X, W, isig2, *, interpret: bool | None = None,
         "aopt_gains", prec, {"dp": dp, "nb": bucket_n(n)}, vmem,
     )
     np_ = round_up(n, bn)
-    if use_ref or dp * np_ > HUGE_ELEMS:
+    if use_ref:
         return aopt_gains_ref(quantize(X, prec), quantize(W, prec), isig2)
     Xp = pad2d(X, dp, np_, dtype=sdt)
     Wp = pad2d(W, dp, np_, dtype=sdt)

@@ -34,9 +34,9 @@ does the same four things before dispatching:
      choose fills so padded entries cannot contribute — zero columns for
      streamed operands, and for guard vectors a fill that trips the
      guard (e.g. ``filter_gains`` pads ``col_sq`` with 1.0 so the span
-     tolerance clamps padded candidates to 0).  If the padded problem
-     exceeds ``HUGE_ELEMS`` elements the wrapper returns the reference
-     instead — padding would dominate the launch.
+     tolerance clamps padded candidates to 0).  Padding adds less than
+     one block of columns, and an operand that is already aligned is
+     passed through uncopied, so it never decides the path.
   4. **Block-size selection** (``repro.kernels.tuning.tuned_block_n``
      over ``pick_block_n``).  The wrapper states its per-grid-step
      working set as bytes(block_n) — inputs + outputs + scratch + large
@@ -63,9 +63,6 @@ import jax.numpy as jnp
 
 # Leave headroom of the 16 MB v5e per-core VMEM for double buffering.
 VMEM_BUDGET = 12 * 1024 * 1024
-# Padded problems larger than this (elements across the streamed
-# operands) stay on the jnp reference: the padding itself would dominate.
-HUGE_ELEMS = 64 * 1024 * 1024
 # Tiling constraints: the lane axis is always 128; the sublane multiple
 # depends on element width — (8, 128) f32 tiles, (16, 128) bf16,
 # (32, 128) int8/fp8.
@@ -158,8 +155,8 @@ def pick_block_n(
 
     ``vmem_bytes`` maps a candidate ``block_n`` to the number of bytes the
     kernel holds resident per grid step (inputs + outputs + scratch).
-    Falls back to the smallest candidate when nothing fits — the kernel
-    then relies on the caller's ``HUGE_ELEMS`` guard.
+    Falls back to the smallest candidate when nothing fits; the TPU
+    compiler then refuses a kernel whose blocks exceed VMEM.
     """
     for bn in candidates:
         if vmem_bytes(bn) <= budget:
@@ -185,8 +182,11 @@ def pad2d(x, rows: int, cols: int, dtype=jnp.float32):
     """Pad a 2-D array up to (rows, cols) with zeros, in ``dtype``.
 
     The cast rides the pad: streaming wrappers pad X directly into its
-    bf16 storage buffer, so quantization costs no extra pass."""
+    bf16 storage buffer, so quantization costs no extra pass.  An
+    operand that already has the target shape is only cast."""
     r, c = x.shape
+    if (r, c) == (rows, cols):
+        return x.astype(dtype)
     return jnp.zeros((rows, cols), dtype).at[:r, :c].set(x.astype(dtype))
 
 

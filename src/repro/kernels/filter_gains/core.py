@@ -44,7 +44,12 @@ each operand *is*; the seven operand kinds are:
   ``gcand``   (G, n)      per-guess per-candidate rows (A-optimality's
                           ‖w_a‖², x_aᵀw_a — functions of the guess's W).
 
-The output is always (G·m, n) f32 with block (1, block_n) at (s, i).
+The output is always (G·m, n) f32; the epilogue writes one (1, block_n)
+row per grid step.  The TPU lowering requires the last two block axes to
+be (8, 128)-aligned or to span the whole array, so one-row blocks of 2-D
+``sample`` / ``gcand`` operands and of the output are taken from a
+(rows, 1, cols) view with the leading axis squeezed out: the epilogue
+still sees (1, ·) refs.
 Grid dimensions are sequential ("arbitrary") by default on TPU, which is
 what lets an epilogue cache sample-independent work in VMEM scratch at
 guess boundaries (``pl.program_id(1) % n_samples == 0``) and reuse it
@@ -94,16 +99,20 @@ def _spec_for(arr, kind: str, block_n: int, m: int) -> pl.BlockSpec:
         return pl.BlockSpec(
             (1, *rest), lambda i, s, _nr=nr, _m=m: (s // _m,) + (0,) * _nr
         )
-    if kind == "sample":
+    if kind == "sample" and arr.ndim == 3:
         rest = arr.shape[1:]
-        nr = len(rest)
-        return pl.BlockSpec(
-            (1, *rest), lambda i, s, _nr=nr: (s,) + (0,) * _nr
-        )
+        return pl.BlockSpec((1, *rest), lambda i, s: (s, 0, 0))
+    if kind == "sample":
+        # 2-D (G·m, r) operands are launched as (G·m, 1, r): a one-row
+        # block must span the whole of the last two axes on TPU.
+        r = arr.shape[1]
+        return pl.BlockSpec((None, 1, r), lambda i, s: (s, 0, 0))
     if kind == "cand":
         return pl.BlockSpec((1, block_n), lambda i, s: (0, i))
     if kind == "gcand":
-        return pl.BlockSpec((1, block_n), lambda i, s, _m=m: (s // _m, i))
+        return pl.BlockSpec(
+            (None, 1, block_n), lambda i, s, _m=m: (s // _m, 0, i)
+        )
     raise ValueError(f"unknown operand kind: {kind!r}")
 
 
@@ -128,30 +137,34 @@ def launch_filter_engine(
     axis 0.  ``sample`` operands must arrive FOLDED: leading axis
     ``n_guesses * n_samples``, guess-major.  ``cand`` operands must be
     passed 1-D; they are reshaped to (1, n) here so the epilogue always
-    sees (1, block_n) refs (``gcand`` operands are already (G, n)).
+    sees (1, block_n) refs (``gcand`` operands are (G, n)).
     Returns (n_guesses·n_samples, n) — callers unfold.
     """
     assert n % block_n == 0, (n, block_n)
     arrays = []
     in_specs = []
     for arr, kind in operands:
-        if kind == "cand":
-            arr = arr[None, :]
         if kind == "sample":
             assert arr.shape[0] == n_guesses * n_samples, (
                 arr.shape, n_guesses, n_samples
             )
+            assert arr.ndim in (2, 3), arr.shape
         if kind in ("gstream", "gconst", "gcand"):
             assert arr.shape[0] == n_guesses, (arr.shape, n_guesses)
-        arrays.append(arr)
         in_specs.append(_spec_for(arr, kind, block_n, n_samples))
+        if kind == "cand":
+            arr = arr[None, :]
+        elif arr.ndim == 2 and kind in ("sample", "gcand"):
+            arr = arr[:, None, :]
+        arrays.append(arr)
     total = n_guesses * n_samples
-    return pl.pallas_call(
+    out = pl.pallas_call(
         body,
         grid=(n // block_n, total),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, block_n), lambda i, s: (s, i)),
-        out_shape=jax.ShapeDtypeStruct((total, n), jnp.float32),
+        out_specs=pl.BlockSpec((None, 1, block_n), lambda i, s: (s, 0, i)),
+        out_shape=jax.ShapeDtypeStruct((total, 1, n), jnp.float32),
         scratch_shapes=list(scratch_shapes),
         interpret=interpret,
     )(*arrays)
+    return out.reshape(total, n)

@@ -47,7 +47,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.common import (
-    HUGE_ELEMS,
     pad1d,
     pad2d,
     quantize,
@@ -107,7 +106,7 @@ def _filter_gains_lattice(X, Q, D, R, col_sq, interpret, precision=None,
         vmem,
     )
     np_ = round_up(n, bn)
-    if use_ref or dp * (np_ + g * kp + g * m * bp) > HUGE_ELEMS:
+    if use_ref:
         return filter_gains_lattice_ref(quantize(X, prec), Q, D, R, col_sq)
 
     Xp = pad2d(X, dp, np_, dtype=sdt)
@@ -229,7 +228,7 @@ def _aopt_filter_gains_lattice(X, W, E, F, isig2, interpret, precision=None,
     # store) and reference agree exactly per precision.
     Xq = quantize(X, prec)
     Wq = quantize(W, prec)
-    if use_ref or dp * ((1 + g) * np_ + g * m * bp) > HUGE_ELEMS:
+    if use_ref:
         return aopt_filter_gains_lattice_ref(Xq, Wq, E, F, isig2)
 
     Xp = pad2d(X, dp, np_, dtype=sdt)
@@ -351,7 +350,7 @@ def _logistic_filter_gains_folded(X, y, etas, steps, interpret,
         vmem,
     )
     np_ = round_up(n, bn)
-    if use_ref or dp * np_ > HUGE_ELEMS:
+    if use_ref:
         return logistic_filter_gains_ref(quantize(X, prec), y, etas,
                                          steps=steps)
 

@@ -30,14 +30,14 @@ def newton_gain_sweep(x, y, eta, *, steps: int, eps: float):
     bn = x.shape[1]
     w = jnp.zeros((1, bn), jnp.float32)
 
-    def newton(w, _):
+    # Static unroll: ``steps`` is small, and the TPU lowering accepts no
+    # ``lax.scan`` inside a kernel.
+    for _ in range(steps):
         z = eta + x * w                 # (d, bn)
         p = jax.nn.sigmoid(z)
         g = jnp.sum(x * (y - p), axis=0, keepdims=True)
         h = jnp.sum((x * x) * (p * (1.0 - p)), axis=0, keepdims=True)
-        return w + g / (h + eps), None
-
-    w, _ = jax.lax.scan(newton, w, None, length=steps)
+        w = w + g / (h + eps)
     z = eta + x * w
     ll_new = jnp.sum(y * z - jax.nn.softplus(z), axis=0, keepdims=True)
     ll_old = jnp.sum(y * eta - jax.nn.softplus(eta))

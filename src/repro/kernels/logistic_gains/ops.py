@@ -12,7 +12,6 @@ identically.
 from __future__ import annotations
 
 from repro.kernels.common import (
-    HUGE_ELEMS,
     pad1d,
     pad2d,
     quantize,
@@ -46,7 +45,7 @@ def logistic_gains(X, y, eta, *, steps: int = 3,
         {"dp": dp, "steps": steps, "nb": bucket_n(n)}, vmem,
     )
     np_ = round_up(n, bn)
-    if use_ref or dp * np_ > HUGE_ELEMS:
+    if use_ref:
         return logistic_gains_ref(quantize(X, prec), y, eta, steps=steps)
     Xp = pad2d(X, dp, np_, dtype=sdt)
     yp = pad1d(y, dp)

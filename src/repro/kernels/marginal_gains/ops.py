@@ -17,7 +17,6 @@ function per precision.
 from __future__ import annotations
 
 from repro.kernels.common import (
-    HUGE_ELEMS,
     pad1d,
     pad2d,
     quantize,
@@ -53,7 +52,7 @@ def regression_gains(X, Q, resid, col_sq, *, interpret: bool | None = None,
         {"dp": dp, "kp": kp, "nb": bucket_n(n)}, vmem,
     )
     np_ = round_up(n, bn)
-    if use_ref or dp * (np_ + kp) > HUGE_ELEMS:
+    if use_ref:
         return regression_gains_ref(quantize(X, prec), Q, resid, col_sq)
 
     Xp = pad2d(X, dp, np_, dtype=sdt)

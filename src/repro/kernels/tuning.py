@@ -15,9 +15,9 @@ the empirical layer:
   fall-through to ``pick_block_n``.  The lookup is pure host-side
   Python on static ints: consulting the cache never adds device work.
 * ``autotune(kernel, precision, dims, run, vmem_bytes, ...)`` — the
-  measurement pass (``bench_kernels --autotune`` and the tpu-bench lane
-  drive it).  For each sublane-legal candidate that fits the budget it
-  times ``run(block_n)`` through the *public wrapper* — so the measured
+  measurement pass (``bench_kernels --autotune`` drives it).  For each
+  sublane-legal candidate that fits the budget it times ``run(block_n)``
+  through the *public wrapper* — so the measured
   path includes padding and dispatch, the thing callers actually pay —
   and persists the winner.  A warm cache short-circuits before any
   measurement: the second invocation performs zero runs (asserted in
@@ -25,12 +25,17 @@ the empirical layer:
 
 Cache file
 ----------
-Versioned JSON at ``$REPRO_TUNING_CACHE`` (default
-``~/.cache/repro/tuning.json``), one entry per backend per key::
+Versioned JSON at ``$REPRO_TUNING_CACHE``, one entry per device kind
+(``jax.devices()[0].device_kind``) per key::
 
     {"version": 1,
-     "entries": {"cpu": {"filter_gains|bf16|dp=1024,kp=128,bp=128,m=8,g=1,nb=4096":
-                         {"block_n": 512, "us_per_call": 1234.5}}}}
+     "entries": {"TPU v5 lite": {"filter_gains|bf16|dp=1024,kp=128,bp=128,m=8,g=1,nb=4096":
+                                 {"block_n": 512, "us_per_call": 1234.5}}}}
+
+Without ``$REPRO_TUNING_CACHE`` no file is read and every wrapper takes
+the ``pick_block_n`` heuristic, so a file outside the checkout can never
+decide what gets compiled; ``autotune`` then refuses to run, since it
+would have nowhere to keep its winners.
 
 Keys bucket shapes exactly like the compiled-launch buckets the
 wrappers already produce — padded dims plus the candidate count rounded
@@ -71,18 +76,18 @@ _LOAD_CACHE: dict[tuple[str, int], dict] = {}
 _MEASUREMENT_RUNS = 0
 
 
-def cache_path() -> Path:
-    """Resolved cache file location (env-overridable)."""
-    override = os.environ.get(ENV_VAR)
-    if override:
-        return Path(override)
-    return Path.home() / ".cache" / "repro" / "tuning.json"
+def cache_path() -> Path | None:
+    """The cache file named by ``$REPRO_TUNING_CACHE``, or None."""
+    path = os.environ.get(ENV_VAR)
+    return Path(path) if path else None
 
 
-def _backend() -> str:
+def device_kind() -> str:
+    """The table a cache entry belongs to: winners measured on one chip
+    generation say nothing about another."""
     import jax
 
-    return jax.default_backend()
+    return jax.devices()[0].device_kind
 
 
 def shape_key(kernel: str, precision: str | None, dims: Mapping[str, int]) -> str:
@@ -110,9 +115,9 @@ def _validate(payload) -> dict:
     entries = payload.get("entries")
     if not isinstance(entries, dict):
         raise ValueError("malformed tuning-cache entries")
-    for backend, table in entries.items():
-        if not isinstance(backend, str) or not isinstance(table, dict):
-            raise ValueError("malformed tuning-cache backend table")
+    for kind, table in entries.items():
+        if not isinstance(kind, str) or not isinstance(table, dict):
+            raise ValueError("malformed tuning-cache device table")
         for key, rec in table.items():
             if not isinstance(key, str) or not isinstance(rec, dict):
                 raise ValueError("malformed tuning-cache record")
@@ -124,6 +129,8 @@ def _validate(payload) -> dict:
 def _load_entries(path: Path | None = None) -> dict:
     """Parsed cache entries; {} on any miss/corruption (never raises)."""
     path = path or cache_path()
+    if path is None:
+        return {}
     try:
         mtime = path.stat().st_mtime_ns
     except OSError:
@@ -144,10 +151,10 @@ def _store_entry(key: str, block_n: int, us_per_call: float, path: Path | None =
     """Merge one winner into the cache file atomically."""
     path = path or cache_path()
     entries = dict(_load_entries(path))
-    backend = _backend()
-    table = dict(entries.get(backend, {}))
+    kind = device_kind()
+    table = dict(entries.get(kind, {}))
     table[key] = {"block_n": int(block_n), "us_per_call": float(us_per_call)}
-    entries[backend] = table
+    entries[kind] = table
     payload = {"version": SCHEMA_VERSION, "entries": entries}
     path.parent.mkdir(parents=True, exist_ok=True)
     fd, tmp = tempfile.mkstemp(dir=str(path.parent), suffix=".tmp")
@@ -170,7 +177,9 @@ def cached_block_n(
 ) -> int | None:
     """Raw cache lookup: the stored winner or None. No validation."""
     entries = _load_entries()
-    rec = entries.get(_backend(), {}).get(shape_key(kernel, precision, dims))
+    if not entries:
+        return None
+    rec = entries.get(device_kind(), {}).get(shape_key(kernel, precision, dims))
     return None if rec is None else rec["block_n"]
 
 
@@ -237,8 +246,12 @@ def autotune(
     the winner.  ``run(block_n)`` must execute the kernel end to end
     through its public wrapper (so padding/dispatch are inside the
     timed region).  Warm cache → returns the stored winner with ZERO
-    measurement runs unless ``force``.
+    measurement runs unless ``force``.  Needs ``$REPRO_TUNING_CACHE``.
     """
+    if cache_path() is None:
+        raise ValueError(
+            f"autotune keeps its winners in ${ENV_VAR}; set it to a file"
+        )
     key = shape_key(kernel, precision, dims)
     if not force:
         cached = cached_block_n(kernel, precision, dims)

@@ -33,19 +33,15 @@ def make_mesh(shape, axes, devices=None):
 
     ``devices`` optionally restricts the mesh to a subset of the host's
     devices (parity tests build a (data, model) submesh next to the full
-    (pod, data, model) lattice mesh this way).  ``axis_types`` only
-    exists on newer jax (explicit-sharding work); every axis here is
-    Auto, which is also the old default — so omit the argument on
-    versions that predate ``jax.sharding.AxisType``.
+    (pod, data, model) lattice mesh this way).  Every axis is Auto: the
+    runners place their operands with explicit ``shard_map`` specs.
     """
     shape, axes = tuple(shape), tuple(axes)
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
-            devices=devices,
-        )
-    return jax.make_mesh(shape, axes, devices=devices)
+    return jax.make_mesh(
+        shape, axes,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+        devices=devices,
+    )
 
 
 def make_lattice_mesh(pod: int, axes=(POD_AXIS, DATA_AXIS, MODEL_AXIS)):

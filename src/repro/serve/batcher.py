@@ -18,8 +18,9 @@ adds only the serve-layer calling convention:
   and inject chaos deterministically; ``rho`` is a traced input, so ONE
   ``step`` compilation serves every round of every B-lane bucket;
 * deterministic tiers (``topk``) run once and broadcast — their lanes
-  are provably identical — while ``stochastic_greedy`` vmaps over lane
-  keys; both are single-shot launches behind the same hedging wrapper.
+  are provably identical — while ``stochastic_greedy`` and ``fast`` vmap
+  over lane keys; all are single-shot launches behind the same hedging
+  wrapper.
 """
 
 from __future__ import annotations
@@ -31,6 +32,7 @@ import jax.numpy as jnp
 
 from repro.core.baselines import top_k_select
 from repro.core.dash import _single_device_hooks
+from repro.core.fast import fast
 from repro.core.greedy import stochastic_greedy
 from repro.core.selection_loop import (
     DashConfig,
@@ -99,16 +101,16 @@ def build_dash_bucket(factory: Callable[[dict], Any],
 
 def build_single_shot(factory: Callable[[dict], Any], tier: str,
                       k: int, **opts) -> Callable:
-    """One-launch executor ``run(arrays, keys) -> BatchOutput`` for the
-    degraded tiers."""
-    if tier == "stochastic_greedy":
+    """One-launch executor ``run(arrays, keys) -> BatchOutput`` for every
+    tier but dash."""
+    keyed = {"stochastic_greedy": stochastic_greedy, "fast": fast}
+    if tier in keyed:
+        algo = keyed[tier]
 
         @jax.jit
         def run(arrays, keys):
             obj = factory(arrays)
-            res = jax.vmap(
-                lambda kk: stochastic_greedy(obj, k, kk, **opts)
-            )(keys)
+            res = jax.vmap(lambda kk: algo(obj, k, kk, **opts))(keys)
             return BatchOutput(
                 sel_mask=res.sel_mask,
                 sel_count=jnp.sum(res.sel_mask.astype(jnp.int32), axis=-1),

@@ -23,6 +23,7 @@ from repro.kernels.filter_gains.ref import (
     filter_gains_ref,
     logistic_filter_gains_ref,
 )
+from repro.kernels.operands import aopt_operands
 
 RNG = np.random.default_rng(0)
 
@@ -162,14 +163,6 @@ def test_dash_end_to_end_with_engine():
 # A-optimality epilogue
 # ---------------------------------------------------------------------------
 
-def _aopt_factors(d, m, b, scale=0.3):
-    """Random Woodbury factors E (m, d, b) + their Grams F = EᵀE."""
-    E = jnp.asarray(RNG.normal(size=(d, max(b, 1), m)) * scale, jnp.float32)
-    E = jnp.moveaxis(E, -1, 0)
-    F = jnp.einsum("mdb,mdc->mbc", E, E)
-    return E, F
-
-
 @pytest.mark.parametrize("d,n,b,m", [
     (32, 64, 1, 2),
     (100, 300, 4, 5),         # n % block_n != 0 → padding
@@ -177,9 +170,7 @@ def _aopt_factors(d, m, b, scale=0.3):
     (64, 1000, 2, 1),         # n_samples = 1
 ])
 def test_aopt_filter_kernel_matches_ref(d, n, b, m):
-    X = jnp.asarray(RNG.normal(size=(d, n)), jnp.float32)
-    W = jnp.asarray(RNG.normal(size=(d, n)), jnp.float32)
-    E, F = _aopt_factors(d, m, b)
+    X, W, E, F = aopt_operands(jax.random.PRNGKey(d + n), d, n, m, b)
     got = aopt_filter_gains(X, W, E, F, 0.7, interpret=True)
     want = aopt_filter_gains_ref(X, W, E, F, 0.7)
     assert got.shape == (m, n)
@@ -495,20 +486,7 @@ def _aopt_operands(d=100, n=300, b=4, m=5):
     # Genuine Woodbury operands (W = M⁻¹X, E = P L⁻ᵀ): random W/E push
     # the epilogue's rational terms into magnitudes where the vs-f32
     # comparison measures conditioning, not bf16 quantization.
-    Xn = RNG.normal(size=(d, n)).astype(np.float32)
-    Xn = Xn / np.linalg.norm(Xn, axis=0, keepdims=True)
-    sel = RNG.choice(n, size=16, replace=False)
-    M = np.eye(d, dtype=np.float32) + Xn[:, sel] @ Xn[:, sel].T
-    W = np.linalg.solve(M, Xn)
-    Es = []
-    for _ in range(m):
-        C = Xn[:, RNG.choice(n, size=b, replace=False)]
-        P = np.linalg.solve(M, C)
-        Lk = np.linalg.cholesky(np.eye(b) + C.T @ P)
-        Es.append(np.linalg.solve(Lk, P.T).T)
-    E = jnp.asarray(np.stack(Es), jnp.float32)
-    F = jnp.einsum("mdb,mdc->mbc", E, E)
-    return jnp.asarray(Xn), jnp.asarray(W), E, F
+    return aopt_operands(jax.random.PRNGKey(3), d, n, m, b)
 
 
 def _logistic_operands(d=100, n=300, m=5):

@@ -226,6 +226,23 @@ class TestServe:
         ref = stochastic_greedy(obj, 5, jax.random.PRNGKey(7))
         np.testing.assert_array_equal(r.sel_mask, np.asarray(ref.sel_mask))
 
+    def test_fast_tier_matches_library(self, data):
+        """A ladder that lists fast serves it as a keyed single-shot
+        tier: each lane commits what a direct select("fast") commits."""
+        from repro.serve.degradation import DegradationLadder
+
+        srv = make_server(data, ladder=DegradationLadder(
+            ("dash", "fast", "stochastic_greedy", "topk")))
+        replies = srv.serve([SelectRequest("toy", 5, s, algo="fast")
+                             for s in (3, 4)])
+        obj = RegressionObjective(data[0], data[1], kmax=KMAX)
+        for s, r in zip((3, 4), replies):
+            assert r.status == OK and r.tier == "fast" and not r.degraded
+            ref = select("fast", obj, 5, jax.random.PRNGKey(s))
+            np.testing.assert_array_equal(r.sel_mask,
+                                          np.asarray(ref.sel_mask))
+            assert r.sel_count == int(ref.sel_count)
+
     def test_topk_tier_broadcasts_deterministic_set(self, data):
         srv = make_server(data)
         replies = srv.serve(

@@ -69,12 +69,12 @@ class TestFallThrough:
 
     def test_stale_schema_version_falls_back(self, cache_file):
         key = tuning.shape_key("k", "f32", DIMS)
-        backend = tuning._backend()
+        kind = tuning.device_kind()
         cache_file.write_text(
             json.dumps(
                 {
                     "version": tuning.SCHEMA_VERSION + 1,
-                    "entries": {backend: {key: {"block_n": 512, "us_per_call": 1.0}}},
+                    "entries": {kind: {key: {"block_n": 512, "us_per_call": 1.0}}},
                 }
             )
         )
@@ -95,6 +95,42 @@ class TestFallThrough:
         assert tuning.tuned_block_n("k", "f32", DIMS, _bytes_flat) == pick_block_n(
             _bytes_flat
         )
+
+
+class TestOptIn:
+    """No file decides a block size unless $REPRO_TUNING_CACHE names it,
+    and entries of one device kind never serve another."""
+
+    def test_home_cache_file_is_never_read(self, tmp_path, monkeypatch):
+        monkeypatch.delenv(tuning.ENV_VAR, raising=False)
+        monkeypatch.setenv("HOME", str(tmp_path))
+        stray = tmp_path / ".cache" / "repro" / "tuning.json"
+        stray.parent.mkdir(parents=True)
+        key = tuning.shape_key("k", "f32", DIMS)
+        stray.write_text(json.dumps({
+            "version": tuning.SCHEMA_VERSION,
+            "entries": {tuning.device_kind(): {key: {"block_n": 128,
+                                                     "us_per_call": 1.0}}},
+        }))
+        assert tuning.cache_path() is None
+        assert tuning.cached_block_n("k", "f32", DIMS) is None
+        assert tuning.tuned_block_n("k", "f32", DIMS, _bytes_flat) == (
+            pick_block_n(_bytes_flat))
+
+    def test_autotune_without_cache_file_refuses(self, monkeypatch):
+        monkeypatch.delenv(tuning.ENV_VAR, raising=False)
+        before = tuning.measurement_runs()
+        with pytest.raises(ValueError, match=tuning.ENV_VAR):
+            tuning.autotune("k", "f32", DIMS, _run, _bytes_flat)
+        assert tuning.measurement_runs() == before
+
+    def test_entries_are_keyed_by_device_kind(self, cache_file):
+        tuning._store_entry(tuning.shape_key("k", "f32", DIMS), 256, 1.0)
+        payload = json.loads(cache_file.read_text())
+        assert list(payload["entries"]) == [tuning.device_kind()]
+        other = {"other chip": payload["entries"].pop(tuning.device_kind())}
+        cache_file.write_text(json.dumps({**payload, "entries": other}))
+        assert tuning.cached_block_n("k", "f32", DIMS) is None
 
 
 class TestWarmCache:
