@@ -31,6 +31,7 @@ from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.core.adaptive_sequencing import adaptive_sequencing
 from repro.core.baselines import random_select, top_k_select
@@ -191,7 +192,15 @@ def select(algo: str, obj, k: int, key=None, mesh=None, **opts) -> SelectionResu
     its :func:`~repro.core.objectives.base.with_precision` view before
     dispatch — it applies uniformly to every registered algorithm on
     both runtimes.
+
+    The call is one ``repro.select`` profiler span (``algo`` and ``k``
+    as its arguments) on the host.
     """
+    with TraceAnnotation("repro.select", algo=algo, k=k):
+        return _select(algo, obj, k, key, mesh, opts)
+
+
+def _select(algo, obj, k, key, mesh, opts) -> SelectionResult:
     spec = get_algorithm(algo)
     k = _validate_k(k)
     precision = opts.pop("precision", None)

@@ -47,6 +47,7 @@ from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from repro.core.estimators import (
     sample_set_batch,
@@ -113,9 +114,12 @@ def _estimate_elem_gains(obj, state, alive, block, allowed, key, cfg):
     if getattr(obj, "use_filter_engine", False):
         gains = obj.filter_gains_batch(state, idx, valid)
     else:
-        gains = jax.vmap(
-            lambda i, v: obj.gains(obj.add_set(state, i, v))
-        )(idx, valid)                           # (m, n) gains w.r.t. S∪R
+        def perturbed_gains(i, v):
+            with jax.named_scope("repro.add_set"):
+                perturbed = obj.add_set(state, i, v)
+            return obj.gains(perturbed)
+
+        gains = jax.vmap(perturbed_gains)(idx, valid)  # (m, n) w.r.t. S∪R
 
     weights = jax.vmap(                         # weight 0 where a ∈ R
         lambda i, v: jnp.ones((n,)).at[i].add(jnp.where(v, -1.0, 0.0))
@@ -133,7 +137,8 @@ def _single_device_hooks(obj, cfg: DashConfig) -> SelectionHooks:
     def pick_and_add(state, alive, allowed, key):
         idx, valid = sample_set_from_mask(key, alive, block)
         valid = valid & (jnp.arange(block) < allowed)
-        state = obj.add_set(state, idx, valid)
+        with jax.named_scope("repro.add_set"):
+            state = obj.add_set(state, idx, valid)
         return state, jnp.sum(valid.astype(jnp.int32))
 
     return SelectionHooks(
@@ -356,6 +361,10 @@ def dash_auto(
     parameter.  ``return_lattice=True`` additionally returns the stacked
     per-guess :class:`DashResult` (leading axis = joint guess, OPT-major)
     for diagnostics and parity tests.
+
+    On the host, the guess set-up, the lattice's dispatch and the argmax
+    are the profiler spans ``repro.dash.guesses``, ``repro.dash.lattice``
+    and ``repro.dash.best``.
     """
     if guess_mode not in ("batched", "vmap", "loop"):
         raise ValueError(f"unknown guess_mode: {guess_mode!r}")
@@ -365,24 +374,28 @@ def dash_auto(
         obj = with_precision(obj, precision)
     cfg = DashConfig(k=k, r=r, eps=eps, alpha=alpha, n_samples=n_samples,
                      trim_frac=trim_frac)
-    guesses = opt_guess_lattice(obj, eps, n_guesses, k)
-    opts, alphas = lattice_grid(guesses, [alpha] if alphas is None else alphas)
-    n_runs = opts.shape[0]
-    keys = jax.random.split(key, n_runs)
+    with TraceAnnotation("repro.dash.guesses"):
+        guesses = opt_guess_lattice(obj, eps, n_guesses, k)
+        opts, alphas = lattice_grid(guesses,
+                                    [alpha] if alphas is None else alphas)
+        n_runs = opts.shape[0]
+        keys = jax.random.split(key, n_runs)
 
-    if guess_mode in ("batched", "vmap"):
-        results = _lattice_runner(obj, cfg, True)(obj, keys, opts, alphas)
-    else:
-        # Debug path: one trace (jit outside the loop — the old code
-        # retraced dash per guess), still no per-guess host sync: results
-        # are stacked and reduced on device.
-        run = _lattice_runner(obj, cfg, False)
-        per_guess = [run(obj, keys[i], opts[i], alphas[i])
-                     for i in range(n_runs)]
-        results = jax.tree_util.tree_map(
-            lambda *xs: jnp.stack(xs), *per_guess
-        )
-    best = _best_of_lattice(results)
+    with TraceAnnotation("repro.dash.lattice"):
+        if guess_mode in ("batched", "vmap"):
+            results = _lattice_runner(obj, cfg, True)(obj, keys, opts, alphas)
+        else:
+            # Debug path: one trace (jit outside the loop — the old code
+            # retraced dash per guess), still no per-guess host sync:
+            # results are stacked and reduced on device.
+            run = _lattice_runner(obj, cfg, False)
+            per_guess = [run(obj, keys[i], opts[i], alphas[i])
+                         for i in range(n_runs)]
+            results = jax.tree_util.tree_map(
+                lambda *xs: jnp.stack(xs), *per_guess
+            )
+    with TraceAnnotation("repro.dash.best"):
+        best = _best_of_lattice(results)
     if return_lattice:
         return best, results
     return best

@@ -250,11 +250,12 @@ def _make_hooks(obj, cfg: DashConfig, X_local, n_global: int,
             # sweep of the local candidate shard for all samples.
             gs = obj.dist_filter_gains_batch(ds, Cs, slot_oks, X_local)
         else:
-            gs = jax.vmap(
-                lambda C, v: obj.dist_gains(
-                    obj.dist_add_set(ds, C, v, X_local), X_local
-                )
-            )(Cs, slot_oks)
+            def perturbed_gains(C, v):
+                with jax.named_scope("repro.add_set"):
+                    perturbed = obj.dist_add_set(ds, C, v, X_local)
+                return obj.dist_gains(perturbed, X_local)
+
+            gs = jax.vmap(perturbed_gains)(Cs, slot_oks)
         gs = jnp.where(sel_local[None, :], 0.0, gs)
 
         if arrived is not None:
@@ -273,7 +274,8 @@ def _make_hooks(obj, cfg: DashConfig, X_local, n_global: int,
     def pick_and_add(state, alive, allowed, key):
         ds, sel_local = state
         idx_l, owned, slot_ok, C = draw(key, alive, allowed)
-        ds = obj.dist_add_set(ds, C, slot_ok, X_local)
+        with jax.named_scope("repro.add_set"):
+            ds = obj.dist_add_set(ds, C, slot_ok, X_local)
         # Scatter ONLY the owned slots: idx_l entries for slots owned
         # by other shards are foreign local indices that can collide
         # with an owned slot's index, and a duplicate-index .set()

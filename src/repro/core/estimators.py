@@ -32,9 +32,10 @@ def sample_set_from_mask(key, mask, m: int):
     (idx, valid): int32 (m,) indices and bool (m,) slot validity (invalid
     slots occur when fewer than m elements are alive).
     """
-    scores = jnp.where(mask, gumbel_noise(key, mask.shape[0]), -jnp.inf)
-    vals, idx = jax.lax.top_k(scores, m)
-    return idx.astype(jnp.int32), jnp.isfinite(vals)
+    with jax.named_scope("repro.sample"):
+        scores = jnp.where(mask, gumbel_noise(key, mask.shape[0]), -jnp.inf)
+        vals, idx = jax.lax.top_k(scores, m)
+        return idx.astype(jnp.int32), jnp.isfinite(vals)
 
 
 def sample_set_batch(key, mask, m: int, n_samples: int):

@@ -334,6 +334,10 @@ def make_round_body(hooks: SelectionHooks, cfg: DashConfig):
     k, r = cfg.k, cfg.r
 
     def round_body(rho, carry: SelectionCarry, opt, alpha) -> SelectionCarry:
+        with jax.named_scope("repro.round"):
+            return _round(rho, carry, opt, alpha)
+
+    def _round(rho, carry: SelectionCarry, opt, alpha) -> SelectionCarry:
         state, alive, count, key, trace = carry
         alpha = jnp.asarray(alpha, jnp.float32)
         alpha2 = alpha * alpha
@@ -345,7 +349,8 @@ def make_round_body(hooks: SelectionHooks, cfg: DashConfig):
         thr_elem = alpha * (1.0 + cfg.eps / 2.0) * t / k
         allowed = jnp.maximum(k - count, 0)
 
-        est0 = hooks.estimate_set_gain(state, alive, allowed, k_est)
+        with jax.named_scope("repro.estimate"):
+            est0 = hooks.estimate_set_gain(state, alive, allowed, k_est)
 
         def cond(w):
             alive_w, key_w, est_w, it = w
@@ -360,12 +365,14 @@ def make_round_body(hooks: SelectionHooks, cfg: DashConfig):
             key_w, k_f, k_e = jax.random.split(key_w, 3)
             eg = hooks.estimate_elem_gains(state, alive_w, allowed, k_f)
             alive_w = alive_w & (eg >= thr_elem) & ~hooks.sel_mask(state)
-            est_w = hooks.estimate_set_gain(state, alive_w, allowed, k_e)
+            with jax.named_scope("repro.estimate"):
+                est_w = hooks.estimate_set_gain(state, alive_w, allowed, k_e)
             return alive_w, key_w, est_w, it + 1
 
-        alive, key, est, iters = jax.lax.while_loop(
-            cond, body, (alive, key, est0, jnp.zeros((), jnp.int32))
-        )
+        with jax.named_scope("repro.filter"):
+            alive, key, est, iters = jax.lax.while_loop(
+                cond, body, (alive, key, est0, jnp.zeros((), jnp.int32))
+            )
 
         state, added = hooks.pick_and_add(state, alive, allowed, k_pick)
         alive = alive & ~hooks.sel_mask(state)

@@ -47,5 +47,6 @@ def aopt_gains_pallas(X, W, *, isig2: float, block_n: int = 256,
         out_specs=pl.BlockSpec((1, block_n), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, n), jnp.float32),
         interpret=interpret,
+        name="aopt_gains_pallas",
     )(X, W)
     return out[0]

@@ -120,6 +120,7 @@ def launch_filter_engine(
     body,
     operands: Sequence[Operand],
     *,
+    name: str,
     n: int,
     n_samples: int,
     block_n: int,
@@ -137,8 +138,10 @@ def launch_filter_engine(
     axis 0.  ``sample`` operands must arrive FOLDED: leading axis
     ``n_guesses * n_samples``, guess-major.  ``cand`` operands must be
     passed 1-D; they are reshaped to (1, n) here so the epilogue always
-    sees (1, block_n) refs (``gcand`` operands are (G, n)).
-    Returns (n_guesses·n_samples, n) — callers unfold.
+    sees (1, block_n) refs (``gcand`` operands are (G, n)).  ``name``
+    is the launch's name in the compiled program and the profile (the
+    calling wrapper's).  Returns (n_guesses·n_samples, n) — callers
+    unfold.
     """
     assert n % block_n == 0, (n, block_n)
     arrays = []
@@ -166,5 +169,6 @@ def launch_filter_engine(
         out_shape=jax.ShapeDtypeStruct((total, 1, n), jnp.float32),
         scratch_shapes=list(scratch_shapes),
         interpret=interpret,
+        name=name,
     )(*arrays)
     return out.reshape(total, n)

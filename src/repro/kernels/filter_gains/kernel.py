@@ -104,6 +104,7 @@ def filter_gains_pallas(
             Operand(R, "sample"),
             Operand(col_sq, "cand"),
         ],
+        name="filter_gains_pallas",
         n=n,
         n_samples=m,
         n_guesses=g,

@@ -92,6 +92,7 @@ def aopt_filter_gains_pallas(
             Operand(wsq, "gcand"),
             Operand(xw, "gcand"),
         ],
+        name="aopt_filter_gains_pallas",
         n=n,
         n_samples=m,
         n_guesses=g,

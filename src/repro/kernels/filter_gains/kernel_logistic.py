@@ -67,6 +67,7 @@ def logistic_filter_gains_pallas(
             Operand(y[:, None], "const"),
             Operand(etas[:, :, None], "sample"),
         ],
+        name="logistic_filter_gains_pallas",
         n=n,
         n_samples=m,
         block_n=block_n,

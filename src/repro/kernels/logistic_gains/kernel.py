@@ -72,5 +72,6 @@ def logistic_gains_pallas(X, y, eta, *, steps: int = 3, block_n: int = 256,
         out_specs=pl.BlockSpec((1, block_n), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, n), jnp.float32),
         interpret=interpret,
+        name="logistic_gains_pallas",
     )(X, y[:, None], eta[:, None])
     return out[0]

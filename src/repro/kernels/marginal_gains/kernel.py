@@ -82,5 +82,6 @@ def regression_gains_pallas(
         out_specs=pl.BlockSpec((1, block_n), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, n), jnp.float32),
         interpret=interpret,
+        name="regression_gains_pallas",
     )(X, Q, resid[:, None], col_sq[None, :])
     return out[0]

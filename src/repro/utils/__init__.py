@@ -4,13 +4,10 @@ from repro.utils.tree import (
     tree_norm,
     tree_zeros_like,
 )
-from repro.utils.timing import Timer, timed
 
 __all__ = [
     "tree_bytes",
     "tree_count",
     "tree_norm",
     "tree_zeros_like",
-    "Timer",
-    "timed",
 ]
