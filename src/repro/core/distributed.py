@@ -75,7 +75,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.core.estimators import gumbel_noise
+from repro.core.estimators import gumbel_noise, top_k_rows
 from repro.core.objectives.base import with_precision
 from repro.core.selection_loop import (
     DashConfig,
@@ -138,7 +138,7 @@ def _dist_sample(key, alive_local, m, n_local, n_global, axis):
     rank = jax.lax.axis_index(axis)
     g = _local_noise_slice(gumbel_noise(key, n_global), rank, n_local)
     scores = jnp.where(alive_local, g, -jnp.inf)
-    loc_vals, loc_idx = jax.lax.top_k(scores, m)
+    loc_vals, loc_idx = top_k_rows(scores, m)             # rank 2 under vmaps
 
     all_vals = jax.lax.all_gather(loc_vals, axis)          # (P, m)
     all_idx = jax.lax.all_gather(loc_idx, axis)            # (P, m)

@@ -11,6 +11,8 @@ values (runtime/straggler.py wires that policy in).
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -24,17 +26,50 @@ def gumbel_noise(key, n: int):
     return -jnp.log(-jnp.log(u))
 
 
+def top_k_rows(x, m: int):
+    """``jax.lax.top_k(x, m)`` over the last axis of ``(..., n)`` ``x``.
+
+    XLA lowers a top-k on TPU to its ``TopK`` op only for rank-1 and
+    rank-2 operands; any higher rank becomes a full sort of n.  Samplers
+    sit under nested ``vmap``s (samples × guesses), so this helper folds
+    every batch axis into one row axis: the body runs ``top_k`` on a
+    ``(rows, n)`` view, and its batching rule moves each enclosing
+    ``vmap``'s axis into those rows instead of adding a rank.  Values and
+    indices are exactly ``lax.top_k``'s.
+    """
+    return _top_k_rows(m)(x)
+
+
+@functools.lru_cache(maxsize=None)
+def _top_k_rows(m: int):
+    @jax.custom_batching.custom_vmap
+    def rows(x):
+        vals, idx = jax.lax.top_k(x.reshape(-1, x.shape[-1]), m)
+        lead = x.shape[:-1] + (m,)
+        return vals.reshape(lead), idx.reshape(lead)
+
+    @rows.def_vmap
+    def _fold(axis_size, in_batched, x):
+        # vmap hands an unbatched operand straight through, so here x
+        # always carries the new axis at the front: it becomes more rows.
+        return rows(x), (True, True)
+
+    return rows
+
+
 def sample_set_from_mask(key, mask, m: int):
     """Uniformly sample ≤ m distinct elements of the alive ``mask``.
 
     Gumbel-top-k trick: taking the top-m of i.i.d. Gumbel noise restricted
     to the alive entries is a uniform without-replacement sample.  Returns
     (idx, valid): int32 (m,) indices and bool (m,) slot validity (invalid
-    slots occur when fewer than m elements are alive).
+    slots occur when fewer than m elements are alive).  The top-m goes
+    through :func:`top_k_rows`, so under the lattice's nested ``vmap``s
+    it stays XLA's rank-2 ``TopK`` rather than a full sort of n.
     """
     with jax.named_scope("repro.sample"):
         scores = jnp.where(mask, gumbel_noise(key, mask.shape[0]), -jnp.inf)
-        vals, idx = jax.lax.top_k(scores, m)
+        vals, idx = top_k_rows(scores, m)
         return idx.astype(jnp.int32), jnp.isfinite(vals)
 
 

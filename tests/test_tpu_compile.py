@@ -12,6 +12,8 @@ load the TPU library, so a worker that is not given this file must not
 touch it.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -104,6 +106,13 @@ def _kernel_case(name, sds):
         X, y, e, precision=p), (sds(D, n), sds(D), sds(M, D)))
 
 
+def _wide_sorts(hlo_text, width):
+    """Sort instructions of a compiled module with a ``width``-wide axis."""
+    dim = re.compile(rf"[\[,]{width}[\],]")
+    return [line for line in hlo_text.splitlines()
+            if " sort(" in line and dim.search(line.split(" sort(")[0])]
+
+
 KERNELS = ("regression_gains", "aopt_gains", "logistic_gains",
            "filter_gains", "aopt_filter_gains", "logistic_filter_gains")
 
@@ -121,6 +130,21 @@ def test_kernel_compiles_for_v5e(name, precision, one_chip, tpu_path):
     assert tpu_path, "the wrapper chose no block size"
     for kernel, bn, nbytes in tpu_path:
         assert nbytes <= VMEM_BUDGET, (kernel, bn, nbytes)
+
+
+def test_nested_vmap_sampler_lowers_to_topk(one_chip, no_persistent_cache):
+    """The lattice's sampler, vmapped over 8 guesses and 8 samples at
+    n = 2^17, m = 5: XLA's ``TopK``, not a full sort of n."""
+    from repro.core.estimators import sample_set_from_mask
+
+    G, S, n, m = 8, 8, 1 << 17, 5
+    keys = jax.ShapeDtypeStruct((G, S, 2), jnp.uint32, sharding=one_chip)
+    masks = jax.ShapeDtypeStruct((G, n), jnp.bool_, sharding=one_chip)
+    draw = jax.vmap(jax.vmap(lambda k, mk: sample_set_from_mask(k, mk, m),
+                             in_axes=(0, None)))
+    text = jax.jit(draw).lower(keys, masks).compile().as_text()
+    assert 'custom_call_target="TopK"' in text
+    assert " sort(" not in text, _wide_sorts(text, n)
 
 
 def test_select_dash_program_compiles_with_data_as_arguments(one_chip,
@@ -146,6 +170,7 @@ def test_select_dash_program_compiles_with_data_as_arguments(one_chip,
     assert "tpu_custom_call" in text
     assert len(text) < 16 * 1024 ** 2, len(text)
     compiled = lowered.compile()
+    assert not _wide_sorts(compiled.as_text(), N_SELECT)
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes >= 4 * D * N_SELECT
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
@@ -186,6 +211,7 @@ def test_sharded_select_dash_compiles_on_v5e_2x2(topo, tpu_path):
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert "all-gather" in text or "all-reduce" in text
+    assert not _wide_sorts(text, n // 4)
     mem = compiled.memory_analysis()          # per device
     assert 4 * D * n // 4 <= mem.argument_size_in_bytes < 2 * D * n
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
