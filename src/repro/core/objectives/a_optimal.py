@@ -5,22 +5,28 @@
 Oracles
 -------
 State carries M = Λ + σ⁻² X_S X_Sᵀ, its Cholesky factor L, and the
-cached shared solve W = M⁻¹X (refreshed once per ``add_set`` so the
-singleton-gain and filter-engine oracles never re-pay the (d, d, n)
-triangular solves).
+cached shared solve W = M⁻¹X.  ``add_set`` of columns C refreshes W by
+the rank-m Woodbury update of M' = M + σ⁻²CCᵀ,
+
+      W' = M'⁻¹X = W − E (EᵀX),   M'⁻¹ = M⁻¹ − EEᵀ,
+
+with E (d, m) built from P = M⁻¹C (one (d, d)·(d, m) solve against the
+old factor), so no oracle ever pays a (d, d)·(d, n) triangular solve.
+Every product of the update runs at HIGHEST precision: W stays M⁻¹X to
+f32 for the M the state carries, round after round.
 
 * Singleton gains (Sherman–Morrison):
       f_S(a) = σ⁻² ‖M⁻¹ x_a‖² / (1 + σ⁻² x_aᵀ M⁻¹ x_a)
-  Batched: W = M⁻¹X is one pair of triangular-solve GEMMs; the remaining
-  fused column-norm/ratio math is ``repro.kernels.aopt_gains``.
+  Batched: W = M⁻¹X is the cached solve; the fused column-norm/ratio
+  math is ``repro.kernels.aopt_gains``.
 * Set gains (Woodbury):
       f_S(R) = σ⁻² Tr( (I + σ⁻² CᵀM⁻¹C)⁻¹ · (M⁻¹C)ᵀ(M⁻¹C) ),  C = X_R.
 * Filter engine (DASH's Ê_R[f_{S∪R}(a)] statistic): the perturbed
   precision M_i = M + σ⁻² C_i C_iᵀ splits as M_i⁻¹ = M⁻¹ − E_i E_iᵀ
   (``expand_factors``), so ``filter_gains_batch`` evaluates all
   ``n_samples`` perturbed states against the SHARED solve W = M⁻¹X in
-  one fused pass (``repro.kernels.filter_gains``) instead of paying two
-  (d, d, n) triangular solves per sample.
+  one fused pass (``repro.kernels.filter_gains``) instead of writing a
+  refreshed (d, n) W per sample.
 """
 
 from __future__ import annotations
@@ -37,7 +43,7 @@ from repro.kernels.common import quantize, resolve_precision
 class AOptState(NamedTuple):
     M: jnp.ndarray          # (d, d) posterior precision
     L: jnp.ndarray          # (d, d) chol(M)
-    W: jnp.ndarray          # (d, n) cached shared solve M⁻¹X
+    W: jnp.ndarray          # (d, n) shared solve M⁻¹X, Woodbury-refreshed
     sel_mask: jnp.ndarray   # (n,) bool
     value: jnp.ndarray      # () f32
 
@@ -45,8 +51,9 @@ class AOptState(NamedTuple):
 class AOptDistState(NamedTuple):
     """Replicated precision/factor state for the distributed runtime.
     ``W`` is the shard-LOCAL shared solve M⁻¹X_local — the only (n,)-
-    shaped member, refreshed once per ``dist_add_set`` like the
-    single-device cache."""
+    shaped member, Woodbury-refreshed by ``dist_add_set`` like the
+    single-device cache (E comes from replicated C and L, so EᵀX_local
+    needs no collective)."""
     M: jnp.ndarray          # (d, d) — replicated
     L: jnp.ndarray          # (d, d) — replicated
     W: jnp.ndarray          # (d, n_local) — shard-local
@@ -145,14 +152,27 @@ class AOptimalityObjective(PytreeObject):
         # semantics, so mask out duplicates.
         new_mask = mask & ~state.sel_mask[idx]
         C = gather_columns(self.X, idx, new_mask)
-        M = state.M + self.isig2 * (C @ C.T)
-        L = self._chol(M)
+        M, L, W = self._update(state.M, state.L, state.W, C, self.X)
         sel = state.sel_mask.at[idx].set(state.sel_mask[idx] | mask)
         value = self.tr_prior - self._trace_inv(L)
-        # The shared solve is refreshed once per state update, so gains()
-        # and the filter engine read it for free.
-        return AOptState(M=M, L=L, W=self._minv(L, self.X), sel_mask=sel,
-                         value=value)
+        return AOptState(M=M, L=L, W=W, sel_mask=sel, value=value)
+
+    def _update(self, M, L, W, C, X):
+        """(M', L', W') for M' = M + σ⁻²CCᵀ — the ONE state update behind
+        ``add_set`` and ``dist_add_set``.  C must be exactly the columns
+        that enter M (padded and duplicate slots zeroed).
+
+        W' = W − E (EᵀX) with E from P = M⁻¹C against the OLD factor L,
+        so each update adds one rounding and none compounds.  EᵀX reads
+        X, not W: under ``vmap`` over states X is unbatched and is read
+        once for all of them.  The products are HIGHEST because a
+        default-precision f32 product on a TPU is one bf16 pass."""
+        hi = jax.lax.Precision.HIGHEST
+        M = M + self.isig2 * jnp.matmul(C, C.T, precision=hi)
+        E, _ = self._woodbury_factors(C, self._minv(L, C))
+        G = jnp.matmul(E.T, X, precision=hi)                   # (m, n)
+        W = W - jnp.matmul(E, G, precision=hi)
+        return M, self._chol(M), W
 
     def add_one(self, state: AOptState, a) -> AOptState:
         idx = jnp.full((1,), a, jnp.int32)
@@ -227,9 +247,11 @@ class AOptimalityObjective(PytreeObject):
     def _woodbury_factors(self, C, P):
         """(E, F) of M + σ⁻²CCᵀ given C and P = M⁻¹C — the ONE
         implementation behind ``expand_factors`` (index-based, with the
-        shared-solve gather) and ``dist_filter_gains_batch``."""
+        shared-solve gather), ``dist_filter_gains_batch`` and the state
+        update ``_update``."""
         m = C.shape[1]
-        K = jnp.eye(m) + self.isig2 * (C.T @ P)
+        K = jnp.eye(m) + self.isig2 * jnp.matmul(
+            C.T, P, precision=jax.lax.Precision.HIGHEST)
         Lk = jnp.linalg.cholesky(K)
         Et = jnp.sqrt(self.isig2) * jax.scipy.linalg.solve_triangular(
             Lk, P.T, lower=True
@@ -260,10 +282,7 @@ class AOptimalityObjective(PytreeObject):
 
     def dist_add_set(self, ds: AOptDistState, C, mask, X_local):
         C = C * mask.astype(C.dtype)[None, :]
-        M = ds.M + self.isig2 * (C @ C.T)
-        L = self._chol(M)
-        # Refresh the shard-local shared solve once per state update.
-        return AOptDistState(M=M, L=L, W=self._minv(L, X_local))
+        return AOptDistState(*self._update(ds.M, ds.L, ds.W, C, X_local))
 
     def dist_filter_gains_batch(self, ds: AOptDistState, Cs, masks, X_local):
         Cs = Cs * masks.astype(Cs.dtype)[:, None, :]

@@ -1,6 +1,6 @@
 """Pure-jnp oracle for the batched A-optimality (Sherman–Morrison) gains.
 
-Given W = M⁻¹X (precomputed by two triangular-solve GEMMs):
+Given W = M⁻¹X (the objective's cached shared solve):
 
     gain(a) = σ⁻² ‖w_a‖² / (1 + σ⁻² x_aᵀ w_a)
 """

@@ -14,8 +14,8 @@ F_i = E_iᵀE_i:
     x_aᵀM_i⁻¹x_a = x_aᵀw_a − ‖t‖²
     gain_ia = σ⁻² ‖M_i⁻¹x_a‖² / (1 + σ⁻² x_aᵀM_i⁻¹x_a)
 
-The per-sample path instead re-factorizes M_i and pays two (d, d, n)
-triangular solves per sample; the engine pays one shared solve plus
+The per-sample path instead re-factorizes M_i and writes a (d, n)
+W_i = W − E_i(E_iᵀX) per sample; the engine reads the one shared W plus
 (m · b · d · n) delta GEMMs — same shape of win as the regression
 epilogue's shared-base projection.
 

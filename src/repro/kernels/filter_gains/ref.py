@@ -17,7 +17,7 @@ small per-sample delta, so the expensive candidate sweep is paid once:
 
 * A-optimality (``aopt_filter_gains_ref``): shared solve W = M⁻¹X plus
   per-sample Woodbury factors E_i with M_i⁻¹ = M⁻¹ − E_i E_iᵀ; the
-  per-sample path pays two (d, d, n) triangular solves per sample.
+  per-sample path writes a fresh (d, n) W_i = W − E_i(E_iᵀX) per sample.
 
 * logistic (``logistic_filter_gains_ref``): per-sample refit logits η_i;
   each row is exactly ``logistic_gains_ref`` at η_i — no shared GEMM,
