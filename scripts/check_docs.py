@@ -5,30 +5,28 @@ Every backticked token in the prose that LOOKS like a repo artifact is
 verified to exist:
 
   * **paths** — ``core/fast.py``, ``src/repro/kernels/``,
-    ``benchmarks/bench_selection.py::run_baselines`` (the ``::symbol``
-    suffix is additionally grepped for inside the resolved file).
+    ``core/algorithms.py::select`` (the ``::symbol`` suffix is
+    additionally grepped for inside the resolved file).
     Bare basenames (``dash.py``) resolve anywhere in the tree; relative
     paths also resolve under ``src/`` and ``src/repro/`` (the docs
     conventionally drop those prefixes).
-  * **``--suite`` names** — validated against the ``known`` set parsed
-    out of ``benchmarks/bench_selection.py`` (parsed, not imported, so
-    the linter runs without jax).
   * **CLI flags** — ``--flag`` tokens validated against the union of
     every ``add_argument("--...")`` in the repo's Python files, plus a
     small allowlist of external flags (XLA, pip, pytest).
   * **``python -m`` modules** — dotted module paths must resolve to a
-    file under the repo (``benchmarks.bench_selection`` →
-    ``benchmarks/bench_selection.py``).
+    file under the repo (``repro.launch.dryrun`` →
+    ``src/repro/launch/dryrun.py``).
 
-Fenced code blocks are scanned for ``--suite`` values, ``python -m``
-modules, and ``*.py`` path arguments (commands must stay runnable);
+The linter parses the tree and imports nothing of it, so it runs
+without jax.  Fenced code blocks are scanned for ``python -m`` modules
+and ``*.py`` path arguments (commands must stay runnable);
 ``--flag`` validation applies to inline backticks only, where a flag is
 a deliberate reference rather than incidental shell text.
 
 Tokens containing placeholders (``<name>``, ``{f32,bf16}``, ``*``) are
 skipped.  Exit status 1 lists every violation; the pytest self-test
 (tests/test_check_docs.py) pins that a doc referencing a nonexistent
-path, suite, or flag fails.
+path, flag or module fails.
 
 Usage:  python scripts/check_docs.py [files...]
         (no args: README.md + docs/*.md)
@@ -57,20 +55,7 @@ _INLINE_CODE = re.compile(r"`([^`\n]+)`")
 _FENCE = re.compile(r"^(```|~~~)")
 _SYMBOL = re.compile(r"[A-Za-z_][A-Za-z0-9_]*")
 _MODULE = re.compile(r"python[0-9.]*\s+-m\s+([A-Za-z_][\w.]*)")
-_SUITE = re.compile(r"--suite[= ]([A-Za-z0-9_,]+)")
-_KNOWN_SET = re.compile(r"known\s*=\s*\{([^}]*)\}", re.S)
 _ADD_ARG = re.compile(r"add_argument\(\s*[\"'](--[A-Za-z0-9][\w-]*)[\"']")
-
-
-def known_suites(repo: Path = REPO) -> set[str]:
-    """The --suite vocabulary, regex-parsed from bench_selection.py."""
-    src = (repo / "benchmarks" / "bench_selection.py").read_text()
-    m = _KNOWN_SET.search(src)
-    if not m:  # pragma: no cover - bench refactor guard
-        raise RuntimeError("cannot find the `known = {...}` suite set in "
-                           "benchmarks/bench_selection.py")
-    return {s.strip().strip("\"'") for s in m.group(1).split(",")
-            if s.strip()}
 
 
 def known_flags(repo: Path = REPO) -> set[str]:
@@ -89,7 +74,7 @@ def known_flags(repo: Path = REPO) -> set[str]:
 #: Runtime-generated artifacts the docs legitimately name although they
 #: are not tracked in the tree.
 _GENERATED = re.compile(
-    r"^(BENCH_\w+\.json|manifest\.json|tuning\.json|\.jax_cache)$")
+    r"^(manifest\.json|tuning\.json|\.jax_cache)$")
 
 
 def _is_placeholder(tok: str) -> bool:
@@ -133,16 +118,6 @@ def _check_pathlike(tok: str, repo: Path, problems: list[str],
                 f"{target.relative_to(repo)}")
 
 
-def _check_suites(text: str, suites: set[str], problems: list[str],
-                  where: str) -> None:
-    for m in _SUITE.finditer(text):
-        for s in m.group(1).split(","):
-            if s and s != "all" and s not in suites:
-                problems.append(
-                    f"{where}: `--suite {s}` — unknown suite "
-                    f"(known: {sorted(suites)})")
-
-
 def _check_module(text: str, repo: Path, problems: list[str],
                   where: str) -> None:
     for m in _MODULE.finditer(text):
@@ -160,19 +135,16 @@ def _check_module(text: str, repo: Path, problems: list[str],
                 f"{where}: `python -m {mod}` — module not found in tree")
 
 
-def _lint_inline(tok: str, repo: Path, suites: set[str],
-                 flags: set[str], problems: list[str],
-                 where: str) -> None:
+def _lint_inline(tok: str, repo: Path, flags: set[str],
+                 problems: list[str], where: str) -> None:
     tok = tok.strip()
     if not tok or _is_placeholder(tok):
         return
     head, *rest = tok.split()
-    tail = " ".join(rest)
     if head.startswith("--"):
         flag = head.split("=")[0]
         if flag not in flags:
             problems.append(f"{where}: unknown CLI flag `{flag}`")
-        _check_suites(tok, suites, problems, where)
         return
     looks_pathy = ("/" in head and not head.startswith("-")) or \
         head.endswith(_PATH_EXTS) or head.split("::")[0].endswith(_PATH_EXTS)
@@ -182,23 +154,21 @@ def _lint_inline(tok: str, repo: Path, suites: set[str],
             return
         if not head.split("::")[0].endswith(_PATH_EXTS) \
                 and not tok.endswith("/"):
-            return  # bench emit keys like `kernels/aopt_gains`
+            return  # names with a slash, like `kernels/aopt_gains`
         _check_pathlike(head if head.split("::")[0].endswith(_PATH_EXTS)
                         else tok, repo, problems, where)
-        # trailing flags in the same token (`script.py --suite serve`)
+        # trailing flags in the same token (`run.py --workload <cell>`)
         for piece in rest:
             if piece.startswith("--"):
                 flag = piece.split("=")[0]
                 if flag not in flags:
                     problems.append(
                         f"{where}: unknown CLI flag `{flag}` (in `{tok}`)")
-        _check_suites(tail, suites, problems, where)
     _check_module(tok, repo, problems, where)
 
 
-def _lint_fenced(block: str, repo: Path, suites: set[str],
-                 problems: list[str], where: str) -> None:
-    _check_suites(block, suites, problems, where)
+def _lint_fenced(block: str, repo: Path, problems: list[str],
+                 where: str) -> None:
     _check_module(block, repo, problems, where)
     for tok in re.findall(r"[\w./-]+\.py\b", block):
         if _is_placeholder(tok) or tok.startswith("-"):
@@ -208,7 +178,6 @@ def _lint_fenced(block: str, repo: Path, suites: set[str],
 
 
 def lint_files(files, repo: Path = REPO) -> list[str]:
-    suites = known_suites(repo)
     flags = known_flags(repo)
     problems: list[str] = []
     for f in files:
@@ -219,8 +188,8 @@ def lint_files(files, repo: Path = REPO) -> list[str]:
         for i, line in enumerate(f.read_text().splitlines(), 1):
             if _FENCE.match(line.strip()):
                 if in_fence:
-                    _lint_fenced("\n".join(fence_buf), repo, suites,
-                                 problems, f"{f.name}:{fence_start}")
+                    _lint_fenced("\n".join(fence_buf), repo, problems,
+                                 f"{f.name}:{fence_start}")
                     fence_buf = []
                 else:
                     fence_start = i
@@ -230,7 +199,7 @@ def lint_files(files, repo: Path = REPO) -> list[str]:
                 fence_buf.append(line)
                 continue
             for m in _INLINE_CODE.finditer(line):
-                _lint_inline(m.group(1), repo, suites, flags, problems,
+                _lint_inline(m.group(1), repo, flags, problems,
                              f"{f.name}:{i}")
     return problems
 

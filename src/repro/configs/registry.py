@@ -1,7 +1,7 @@
 """Architecture registry: every assigned arch id → ModelConfig.
 
 ``get_config(arch_id)`` / ``--arch <id>`` is the selection mechanism for
-launchers, dry-runs and benchmarks.  ``runnable_cells()`` enumerates the
+launchers and dry-runs.  ``runnable_cells()`` enumerates the
 assigned (arch × shape) grid with the documented long_500k skips.
 """
 

@@ -12,16 +12,15 @@ owns the roster once:
 Every algorithm is registered as an :class:`AlgorithmSpec` pairing its
 single-device implementation with its distributed twin (expressed
 against the ``DistributedObjective`` contract — see
-``core.distributed``), plus an adaptivity/query cost model for the
-benchmark tables and docs/algorithms.md.  ``select`` dispatches on
-``mesh`` and normalizes every native result type into one
-:class:`SelectionResult` so benchmarks, tests and serving code can loop
-over algorithms without per-algorithm unpacking.
+``core.distributed``), plus an adaptivity/query cost model for
+docs/algorithms.md.  ``select`` dispatches on ``mesh`` and normalizes
+every native result type into one :class:`SelectionResult` so
+benchmarks, tests and serving code can loop over algorithms without
+per-algorithm unpacking.
 
 Adding an algorithm = one ``register(AlgorithmSpec(...))`` call; the
-benchmark suite (``bench_selection --suite baselines``) and the parity
-tests iterate the registry, so a new entry is benched and parity-tested
-for free.
+registry tests and the parity tests iterate the registry, so a new
+entry is tested for free.
 """
 
 from __future__ import annotations
@@ -73,7 +72,7 @@ class AlgorithmSpec:
     result type; ``select`` normalizes.  ``needs_key`` marks randomized
     algorithms (``select`` defaults their key deterministically).
     ``cost(n, k)`` returns the ``{"oracle_calls", "adaptive_rounds"}``
-    accounting used by docs/algorithms.md and the benchmark tables.
+    accounting used by docs/algorithms.md.
     """
 
     name: str

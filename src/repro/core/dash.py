@@ -305,8 +305,8 @@ def _best_of_lattice(results: DashResult) -> DashResult:
     return jax.tree_util.tree_map(lambda x: x[best], results)
 
 
-def _lattice_runner(obj, cfg: DashConfig, batched: bool):
-    """Jitted lattice executors ``run(obj, keys, opts, alphas)``, cached
+def _lattice_runner(obj, cfg: DashConfig):
+    """Jitted lattice executor ``run(obj, keys, opts, alphas)``, cached
     per objective (weakly — see :func:`core.selection_loop.cached_runner`).
 
     ``dash_auto`` is called repeatedly with the same objective (guess
@@ -318,12 +318,9 @@ def _lattice_runner(obj, cfg: DashConfig, batched: bool):
     def run(o, kk, g, a):
         return dash(o, cfg, kk, g, a)
 
-    def build():
-        if batched:
-            return jax.jit(jax.vmap(run, in_axes=(None, 0, 0, 0)))
-        return jax.jit(run)
-
-    return cached_runner(obj, ("lattice", cfg, batched), build)
+    return cached_runner(
+        obj, ("lattice", cfg),
+        lambda: jax.jit(jax.vmap(run, in_axes=(None, 0, 0, 0))))
 
 
 def dash_auto(
@@ -338,22 +335,19 @@ def dash_auto(
     n_guesses: int = 8,
     trim_frac: float = 0.0,
     alphas=None,
-    guess_mode: str = "batched",
     return_lattice: bool = False,
     precision: str | None = None,
 ):
     """DASH with the (OPT, α) guess lattice; returns the best solution.
 
-    The default ``guess_mode="batched"`` runs the WHOLE lattice as one
-    jitted vmapped computation: all guesses' selection loops advance in
-    lockstep under a single compilation, the filter sweeps ride the
-    guess-folded filter engine (one fused launch for all G·n_samples
-    perturbed states — see ``repro.kernels.filter_gains``), and the best
-    guess is committed by a device-side argmax, so the host never syncs
-    per guess.  ``guess_mode="loop"`` is kept as a DEBUG mode only
-    (per-guess executions are easier to bisect); it jits ``dash`` once
-    and still reduces on device.  ``"vmap"`` is accepted as a legacy
-    alias for ``"batched"``.
+    The WHOLE lattice runs as one jitted vmapped computation: all
+    guesses' selection loops advance in lockstep under a single
+    compilation, the filter sweeps ride the guess-folded filter engine
+    (one fused launch for all G·n_samples perturbed states — see
+    ``repro.kernels.filter_gains``), and the best guess is committed by
+    a device-side argmax, so the host never syncs per guess.  To look at
+    one guess alone, run ``jax.jit(dash, static_argnums=1)`` on that
+    lane's key, OPT and α.
 
     ``alphas`` optionally adds an α lattice: the runs sweep the full
     (OPT, α) cross product (``n_guesses · len(alphas)`` joint guesses),
@@ -366,8 +360,6 @@ def dash_auto(
     are the profiler spans ``repro.dash.guesses``, ``repro.dash.lattice``
     and ``repro.dash.best``.
     """
-    if guess_mode not in ("batched", "vmap", "loop"):
-        raise ValueError(f"unknown guess_mode: {guess_mode!r}")
     if precision is not None:
         # Applied before the lattice runner so the compiled runner is
         # cached on (and keyed by) the precision view.
@@ -378,22 +370,10 @@ def dash_auto(
         guesses = opt_guess_lattice(obj, eps, n_guesses, k)
         opts, alphas = lattice_grid(guesses,
                                     [alpha] if alphas is None else alphas)
-        n_runs = opts.shape[0]
-        keys = jax.random.split(key, n_runs)
+        keys = jax.random.split(key, opts.shape[0])
 
     with TraceAnnotation("repro.dash.lattice"):
-        if guess_mode in ("batched", "vmap"):
-            results = _lattice_runner(obj, cfg, True)(obj, keys, opts, alphas)
-        else:
-            # Debug path: one trace (jit outside the loop — the old code
-            # retraced dash per guess), still no per-guess host sync:
-            # results are stacked and reduced on device.
-            run = _lattice_runner(obj, cfg, False)
-            per_guess = [run(obj, keys[i], opts[i], alphas[i])
-                         for i in range(n_runs)]
-            results = jax.tree_util.tree_map(
-                lambda *xs: jnp.stack(xs), *per_guess
-            )
+        results = _lattice_runner(obj, cfg)(obj, keys, opts, alphas)
     with TraceAnnotation("repro.dash.best"):
         best = _best_of_lattice(results)
     if return_lattice:

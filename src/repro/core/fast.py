@@ -49,9 +49,9 @@ original ``core.adaptive_sequencing`` (which that module now also
 routes through :func:`sequence_prefix_gains`).
 
 Compared to lazy greedy (the strong practical competitor), FAST trades
-k sequential host-driven picks for a handful of fused device rounds:
-on the jitted time-vs-n bench it wins wall-clock at matched objective
-value (``--suite baselines``, ``baselines/time_vs_n`` rows).
+k sequential host-driven picks for a handful of fused device rounds, to
+win wall-clock at matched objective value; no chip run has measured
+that comparison yet.
 
 See docs/fast.md for the full semantics and the distributed twin's
 collectives table (``core.distributed.fast_distributed``).

@@ -15,8 +15,8 @@
                       time through the objective's fused subset-gain
                       oracle (``gains_subset``).  Exact for submodular f,
                       strong heuristic otherwise — beyond-paper baseline.
-``greedy_*_cost``   — adaptivity/query accounting helpers for the
-                      benchmark tables and docs/algorithms.md.
+``greedy_*_cost``   — adaptivity/query accounting helpers for
+                      docs/algorithms.md.
 
 Distributed twins (``greedy_distributed``, ``stochastic_greedy_distributed``)
 live in ``core.distributed`` next to the sharded DASH runtime; the

@@ -15,7 +15,7 @@ the empirical layer:
   fall-through to ``pick_block_n``.  The lookup is pure host-side
   Python on static ints: consulting the cache never adds device work.
 * ``autotune(kernel, precision, dims, run, vmem_bytes, ...)`` — the
-  measurement pass (``bench_kernels --autotune`` drives it).  For each
+  measurement pass; only its tests call it.  For each
   sublane-legal candidate that fits the budget it times ``run(block_n)``
   through the *public wrapper* — so the measured
   path includes padding and dispatch, the thing callers actually pay —

@@ -15,8 +15,7 @@ allocation).  It records, per cell:
   * collective bytes by op kind (parsed from the optimized HLO, scan
     trip counts folded in),
 
-into results/dryrun.json, which benchmarks/roofline.py turns into the
-EXPERIMENTS.md §Roofline table.
+into results/dryrun.json.
 
 Usage:
   python -m repro.launch.dryrun --arch smollm-135m --shape train_4k

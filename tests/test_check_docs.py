@@ -1,7 +1,7 @@
 """Self-test for the doc-lint (scripts/check_docs.py).
 
 Two halves: the real docs must be clean (the CI gate), and a doctored
-doc referencing a nonexistent path / suite / flag MUST fail — a linter
+doc referencing a nonexistent path / flag / module MUST fail — a linter
 that never fires is worse than none.  No jax import anywhere in this
 path, so the test runs in any lane.
 """
@@ -20,14 +20,10 @@ spec.loader.exec_module(check_docs)
 
 
 class TestVocabulary:
-    def test_known_suites_parsed(self):
-        suites = check_docs.known_suites()
-        assert {"paper", "baselines", "distributed"} <= suites
-
     def test_known_flags_collected(self):
         flags = check_docs.known_flags()
-        assert "--suite" in flags
-        assert "--json" in flags
+        # registered by bench/harness/runner.py
+        assert "--workload" in flags
         # the allowlist rides along
         assert "--xla_force_host_platform_device_count" in flags
 
@@ -42,7 +38,7 @@ class TestRealDocs:
 
 class TestCatchesDrift:
     """The acceptance criterion: a doc referencing a nonexistent
-    path/flag/suite must FAIL the lint."""
+    path/flag/module must FAIL the lint."""
 
     def _lint_text(self, tmp_path, text):
         doc = tmp_path / "doc.md"
@@ -59,12 +55,6 @@ class TestCatchesDrift:
             tmp_path,
             "Entry point: `core/fast.py::definitely_not_a_symbol`.\n")
         assert len(probs) == 1 and "definitely_not_a_symbol" in probs[0]
-
-    def test_bogus_suite_fails(self, tmp_path):
-        probs = self._lint_text(
-            tmp_path,
-            "Run `benchmarks/bench_selection.py --suite nonexistent`.\n")
-        assert len(probs) == 1 and "nonexistent" in probs[0]
 
     def test_bogus_flag_fails(self, tmp_path):
         probs = self._lint_text(
@@ -84,8 +74,8 @@ class TestCatchesDrift:
 
     def test_placeholders_and_artifacts_skipped(self, tmp_path):
         probs = self._lint_text(tmp_path, "\n".join([
-            "Writes `BENCH_selection.json` and `~/.cache/repro/tuning.json`;",
-            "layout `kernels/<name>/{f32,bf16}` with `BENCH_*.json` rows;",
+            "Writes `~/.cache/repro/tuning.json` and `manifest.json`;",
+            "layout `kernels/<name>/{f32,bf16}` with `<cell>.json` rows;",
             "emit keys like `kernels/aopt_gains` are not paths.",
         ]) + "\n")
         assert probs == []
@@ -93,9 +83,9 @@ class TestCatchesDrift:
     def test_good_doc_passes(self, tmp_path):
         probs = self._lint_text(tmp_path, "\n".join([
             "Dispatch lives in `core/algorithms.py::select`; run",
-            "`benchmarks/bench_selection.py --suite baselines` or",
+            "`bench/run.py --workload d1-design.dash` or",
             "```",
-            "PYTHONPATH=src python -m benchmarks.bench_selection --suite serve",
+            "python3 chip_smoke.py --chips 4",
             "```",
         ]) + "\n")
         assert probs == []

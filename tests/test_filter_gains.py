@@ -440,21 +440,31 @@ def test_vmap_with_unbatched_state_broadcasts():
 
 
 def test_batched_dash_auto_equals_loop_with_engine():
-    """End-to-end: the batched lattice (vmapped dash → custom-vmap →
-    folded engine) reproduces loop-mode per-guess results on an
-    engine-enabled objective."""
-    from repro.core import dash_auto
+    """End-to-end: each lane of the batched lattice (vmapped dash →
+    custom-vmap → folded engine) reproduces a standalone jitted ``dash``
+    run on that lane's key, OPT and α, on an engine-enabled objective.
+    Sets match exactly; values to rtol 1e-6, since vmap changes the f32
+    fusion order (1 ulp on the CPU)."""
+    from repro.core.dash import (dash, dash_auto, lattice_grid,
+                                 opt_guess_lattice)
 
     obj = _problem(use_filter_engine=True)
     key = jax.random.PRNGKey(1)
-    kw = dict(eps=0.25, alpha=0.6, n_samples=4, n_guesses=3,
-              return_lattice=True)
-    _, lat_b = dash_auto(obj, obj.kmax, key, guess_mode="batched", **kw)
-    _, lat_l = dash_auto(obj, obj.kmax, key, guess_mode="loop", **kw)
-    np.testing.assert_array_equal(np.asarray(lat_b.value),
-                                  np.asarray(lat_l.value))
-    np.testing.assert_array_equal(np.asarray(lat_b.sel_mask),
-                                  np.asarray(lat_l.sel_mask))
+    k, eps, alpha, n_samples, n_guesses = obj.kmax, 0.25, 0.6, 4, 3
+    _, lat_b = dash_auto(obj, k, key, eps=eps, alpha=alpha,
+                         n_samples=n_samples, n_guesses=n_guesses,
+                         return_lattice=True)
+    cfg = DashConfig(k=k, eps=eps, alpha=alpha, n_samples=n_samples)
+    opts, alphas = lattice_grid(
+        opt_guess_lattice(obj, eps, n_guesses, k), [alpha])
+    keys = jax.random.split(key, opts.shape[0])
+    run = jax.jit(dash, static_argnums=1)
+    for i in range(opts.shape[0]):
+        lane = run(obj, cfg, keys[i], opts[i], alphas[i])
+        np.testing.assert_array_equal(np.asarray(lat_b.sel_mask[i]),
+                                      np.asarray(lane.sel_mask))
+        np.testing.assert_allclose(float(lat_b.value[i]),
+                                   float(lane.value), rtol=1e-6)
 
 
 def test_dash_end_to_end_cls_engine():
